@@ -65,6 +65,11 @@ class PipelineEngine:
         annotated = init_params(jax.random.PRNGKey(seed), cfg)
         self.params_full, self.axes_full = split_annotations(annotated)
         self.opt_state = optimizer.init(self.params_full) if optimizer else None
+        # one compiled update that reuses the old state's buffers: the master
+        # state lives on the default device, which otherwise holds old and
+        # new params and moments at once
+        self._update = (jax.jit(optimizer.update, donate_argnums=(1, 2))
+                        if optimizer else None)
         self.step = 0
         self.plan = None
         self.meshes: dict = {}
@@ -73,9 +78,17 @@ class PipelineEngine:
 
     # ----------------------------------------------------------- plan mgmt
     def _mesh_for(self, stage_plan):
-        devs = [self.devices[d % len(self.devices)] for d in stage_plan.devices]
-        # fewer physical devices than the plan's TP degree (CPU smoke runs):
-        # degrade to the unique device set — semantics preserved, TP emulated
+        n = len(self.devices)
+        if self.devices[0].platform != "cpu":
+            missing = [d for d in stage_plan.devices if d >= n]
+            if missing:
+                raise ValueError(
+                    f"plan names devices {missing} but only {n} exist")
+            devs = [self.devices[d] for d in stage_plan.devices]
+            return make_stage_mesh(devs, 1, len(devs))
+        # CPU smoke runs with fewer host devices than the plan names: wrap
+        # ids and collapse a stage to one device — semantics kept, TP emulated
+        devs = [self.devices[d % n] for d in stage_plan.devices]
         uniq = list(dict.fromkeys(devs))
         if len(uniq) < len(devs):
             devs = uniq[:1]
@@ -324,6 +337,6 @@ class PipelineEngine:
                 full_grads["final_norm"] = reduced["final_norm"]
                 if "lm_head" in reduced:
                     full_grads["lm_head"] = reduced["lm_head"]
-        self.params_full, self.opt_state = self.optimizer.update(
+        self.params_full, self.opt_state = self._update(
             full_grads, self.opt_state, self.params_full, jnp.asarray(self.step))
         self.step += 1

@@ -8,8 +8,9 @@ rules out. This kernel makes the paper's cost model physically true on TPU:
   * grid (B, H, nQ, nK) with the KV dimension innermost ("arbitrary"
     semantics) so flash accumulators live in VMEM scratch across KV steps;
   * per-tile skip predicate from precomputed block metadata (segment-id and
-    position ranges): tiles with no segment overlap, or entirely above the
-    causal diagonal / outside the sliding window, execute no MXU work;
+    position ranges), scalar-prefetched into SMEM: tiles with no segment
+    overlap, or entirely above the causal diagonal / outside the sliding
+    window, execute no MXU work;
   * BlockSpec tiling: q (1,1,bq,dh), k/v (1,1,bk,dh) in VMEM; bq=bk=128 by
     default — MXU-aligned (128x128) and small enough that q,k,v,acc tiles
     (~4 x 128 x head_dim x 4B) stay well under the ~16 MB v5e VMEM budget;
@@ -17,7 +18,9 @@ rules out. This kernel makes the paper's cost model physically true on TPU:
   * GQA via index-map head folding (kv head = h * K // H).
 
 Validated in interpret mode against `repro.kernels.ref.packed_attention_ref`
-across shape/dtype/window sweeps in tests/test_kernels.py.
+across shape/dtype/window sweeps in tests/test_kernels.py; compiled for a
+described v5e chip in tests/test_chip_compile.py; run natively against the
+reference by chip_smoke.py.
 """
 from __future__ import annotations
 
@@ -28,24 +31,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU scratch/compiler params (available in interpret mode too)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _attn_kernel(
+    # scalar prefetch (SMEM): flat (B * nQ * nK,) tile-skip table
+    blk_ok_ref,
     # inputs (per BlockSpec tile)
-    blk_ok_ref, q_ref, k_ref, v_ref, segq_ref, segk_ref, posq_ref, posk_ref,
+    q_ref, k_ref, v_ref, segq_ref, segk_ref, posq_ref, posk_ref,
     # output
     o_ref,
     # scratch
     acc_ref, m_ref, l_ref,
-    *, scale, causal, window, n_k_blocks,
+    *, scale, causal, window, n_q_blocks, n_k_blocks,
 ):
-    ik = pl.program_id(3)
+    b, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
 
     @pl.when(ik == 0)
     def _init():
@@ -53,7 +55,7 @@ def _attn_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(blk_ok_ref[0, 0, 0] != 0)
+    @pl.when(blk_ok_ref[(b * n_q_blocks + iq) * n_k_blocks + ik] != 0)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # (bq, dh)
         k = k_ref[0, 0].astype(jnp.float32)  # (bk, dh)
@@ -62,24 +64,24 @@ def _attn_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, bk)
 
-        seg_q = segq_ref[0]  # (bq,)
-        seg_k = segk_ref[0]  # (bk,)
+        seg_q = segq_ref[0]  # (bq, 1) column
+        seg_k = segk_ref[0]  # (1, bk) row
         pos_q = posq_ref[0]
         pos_k = posk_ref[0]
-        mask = (seg_q[:, None] == seg_k[None, :]) & (seg_q[:, None] != 0)
+        mask = (seg_q == seg_k) & (seg_q != 0)
         if causal:
-            mask &= pos_q[:, None] >= pos_k[None, :]
+            mask &= pos_q >= pos_k
         if window is not None:
-            mask &= (pos_q[:, None] - pos_k[None, :]) < window
+            mask &= (pos_q - pos_k) < window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]  # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         m_ref[...] = m_new
@@ -88,7 +90,7 @@ def _attn_kernel(
     def _finalize():
         l = l_ref[...]
         safe = jnp.maximum(l, 1e-30)
-        out = jnp.where(l[:, None] > 0, acc_ref[...] / safe[:, None], 0.0)
+        out = jnp.where(l > 0, acc_ref[...] / safe, 0.0)
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
@@ -164,54 +166,45 @@ def packed_flash_attention(q, k, v, seg_q, seg_k, pos_q, pos_k, *,
     qt = q_p.transpose(0, 2, 1, 3)
     kt = k_p.transpose(0, 2, 1, 3)
     vt = v_p.transpose(0, 2, 1, 3)
+    # TPU tiling wants the last two block dims to be multiples of (8, 128) or
+    # whole: q-side ids become (B, S, 1) columns, k-side ids (B, 1, S) rows,
+    # so the (bq, bk) mask is a broadcast with no in-kernel transpose.
+    col = lambda x: x[:, :, None]
+    row = lambda x: x[:, None, :]
 
     kernel = functools.partial(
-        _attn_kernel, scale=scale, causal=causal, window=window, n_k_blocks=nk)
+        _attn_kernel, scale=scale, causal=causal, window=window,
+        n_q_blocks=nq, n_k_blocks=nk)
 
-    grid = (B, H, nq, nk)
     kv_head = lambda h: h * K // H
-    in_specs = [
-        pl.BlockSpec((1, 1, 1), lambda b, h, iq, ik: (b, iq, ik)),  # blk_ok
-        pl.BlockSpec((1, 1, bq, dh), lambda b, h, iq, ik: (b, h, iq, 0)),  # q
-        pl.BlockSpec((1, 1, bk, dh), lambda b, h, iq, ik: (b, kv_head(h), ik, 0)),
-        pl.BlockSpec((1, 1, bk, dh), lambda b, h, iq, ik: (b, kv_head(h), ik, 0)),
-        pl.BlockSpec((1, bq), lambda b, h, iq, ik: (b, iq)),  # seg_q
-        pl.BlockSpec((1, bk), lambda b, h, iq, ik: (b, ik)),  # seg_k
-        pl.BlockSpec((1, bq), lambda b, h, iq, ik: (b, iq)),  # pos_q
-        pl.BlockSpec((1, bk), lambda b, h, iq, ik: (b, ik)),  # pos_k
-    ]
-    out_spec = pl.BlockSpec((1, 1, bq, dh), lambda b, h, iq, ik: (b, h, iq, 0))
-    scratch = []
-    compiler_params = None
-    if pltpu is not None:
-        scratch = [
-            pltpu.VMEM((bq, dh), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-        ]
-        try:
-            compiler_params = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
-        except (AttributeError, TypeError):
-            try:
-                compiler_params = pltpu.TPUCompilerParams(
-                    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
-            except AttributeError:
-                compiler_params = None
-
-    kw = {}
-    if compiler_params is not None:
-        kw["compiler_params"] = compiler_params
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # blk_ok, flattened into SMEM
+        grid=(B, H, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, dh), lambda b, h, iq, ik, ok: (b, h, iq, 0)),  # q
+            pl.BlockSpec((1, 1, bk, dh), lambda b, h, iq, ik, ok: (b, kv_head(h), ik, 0)),
+            pl.BlockSpec((1, 1, bk, dh), lambda b, h, iq, ik, ok: (b, kv_head(h), ik, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, h, iq, ik, ok: (b, iq, 0)),  # seg_q
+            pl.BlockSpec((1, 1, bk), lambda b, h, iq, ik, ok: (b, 0, ik)),  # seg_k
+            pl.BlockSpec((1, bq, 1), lambda b, h, iq, ik, ok: (b, iq, 0)),  # pos_q
+            pl.BlockSpec((1, 1, bk), lambda b, h, iq, ik, ok: (b, 0, ik)),  # pos_k
+        ],
+        out_specs=pl.BlockSpec((1, 1, bq, dh), lambda b, h, iq, ik, ok: (b, h, iq, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((bq, dh), jnp.float32),  # acc
+            pltpu.VMEM((bq, 1), jnp.float32),  # running max
+            pltpu.VMEM((bq, 1), jnp.float32),  # running sum
+        ],
+    )
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq_p, dh), q.dtype),
-        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kw,
-    )(blk_ok, qt, kt, vt, seg_q_p, seg_k_p, pos_q_p, pos_k_p)
+    )(blk_ok.astype(jnp.int32).reshape(-1), qt, kt, vt,
+      col(seg_q_p), row(seg_k_p), col(pos_q_p), row(pos_k_p))
     out = out.transpose(0, 2, 1, 3)
     return out[:, :Sq]
 

@@ -6,14 +6,18 @@ Dense masked softmax with exactly the kernel's semantics:
   * optional sliding window (pos_q - pos_k < window),
   * GQA (kv heads repeated to query heads),
   * rows with no visible key return 0 (matches the kernel's safe divide).
+
+Matmuls run at highest precision, so on a TPU the oracle is truly float32.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e30
 
 
+@jax.default_matmul_precision("highest")
 def packed_attention_ref(q, k, v, seg_q, seg_k, pos_q, pos_k, *,
                          causal=True, window=None, scale=None):
     """q (B,Sq,H,dh); k/v (B,Sk,K,dh); seg/pos (B,S) int32 -> (B,Sq,H,dh)."""

@@ -56,6 +56,26 @@ from repro.train.optimizer import optimizer_for
 from repro.train.train_step import build_train_step, init_train_state, sharding_for_state
 
 
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache():
+    """Keep compiled programs across processes. JAX reads
+    JAX_COMPILATION_CACHE_DIR itself; without it the cache lives at a fixed
+    path inside the checkout, so the next run finds it again.
+
+    A program that spans several TPU chips halts the chip when it is read
+    back from the cache (v5e, jax 0.9.0 / libtpu 0.0.34: a psum over two
+    chips runs when compiled and halts when loaded), so a process that sees
+    more than one TPU chip keeps the cache off."""
+    devices = jax.devices()
+    if devices[0].platform == "tpu" and len(devices) > 1:
+        jax.config.update("jax_enable_compilation_cache", False)
+        return
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(REPO_ROOT / ".jax_cache"))
+
+
 def _parse_inject(spec):
     """'step:device[,step:device...]' -> [(step, device)]."""
     out = []
@@ -82,9 +102,7 @@ def run_spmd(cfg, args):
         state_sh, _, _ = sharding_for_state(policy, cfg, opt)
         state = jax.tree.map(
             lambda x, s: jax.device_put(x, s) if s is not None else x, state, state_sh)
-    step_fn = jax.jit(build_train_step(
-        cfg, policy, opt, microbatches=args.microbatches, remat=True,
-        flash_chunk=max(args.seq_len // 4, 16)))
+    step_fn = spmd_step(cfg, policy, opt, args)
 
     ckpt = CheckpointManager(args.ckpt_dir, interval=args.ckpt_interval) if args.ckpt_dir else None
     start = 0
@@ -100,16 +118,21 @@ def run_spmd(cfg, args):
         heartbeat=HeartbeatMonitor(),
         changepoint_factory=lambda: CusumDetector(warmup=8),
     )
-    losses, times = [], []
+    losses, times, compile_s = [], [], None
     for it in range(start, args.steps):
         batch = {k: jnp.asarray(v) for k, v in ds.batch_at(it).items()}
+        if compile_s is None:
+            t0 = time.perf_counter()
+            step_fn = step_fn.lower(state, batch).compile()
+            compile_s = time.perf_counter() - t0
         t0 = time.perf_counter()
         state, metrics = step_fn(state, batch)
-        loss = float(metrics["loss"])
+        jax.block_until_ready(state)
         dt = time.perf_counter() - t0
+        loss = float(metrics["loss"])
         stats = pack_stats(np.asarray(batch["segment_ids"]))
         n, l2 = sum(s[0] for s in stats), sum(s[1] for s in stats)
-        if it - start >= 2:  # skip compile iterations
+        if it - start >= 2:  # skip warm-up iterations
             pred.observe(n, l2, dt)
             if len(pred._obs) >= 4 and not pred.fitted:
                 pred.fit()
@@ -120,8 +143,16 @@ def run_spmd(cfg, args):
             ckpt.maybe_save(state, it + 1, extra={"loss": loss})
         if it % max(args.steps // 10, 1) == 0 or it == args.steps - 1:
             print(f"[train] step {it} loss {loss:.4f} {dt*1e3:.0f} ms")
-    return {"losses": losses, "times": times,
+    return {"losses": losses, "times": times, "compile_s": compile_s,
             "detector": detector.stats.as_dict()}
+
+
+def spmd_step(cfg, policy, opt, args):
+    """The jitted SPMD train step (also compiled for a described chip by
+    tests/test_chip_compile.py)."""
+    return jax.jit(build_train_step(
+        cfg, policy, opt, microbatches=args.microbatches, remat=True,
+        flash_chunk=max(args.seq_len // 4, 16)))
 
 
 # ------------------------------------------------------------ pipeline mode
@@ -163,8 +194,15 @@ def run_pipeline(cfg, args):
         engine.step = int(full["step"]) if not isinstance(full["step"], int) else full["step"]
         print(f"[train] resumed from step {start}")
 
+    def placement(it):  # plan device ids vs the devices each stage mesh holds
+        return {"step": it, "stages": [
+            {"stage": [r, s], "plan": list(engine.plan.stage(r, s).devices),
+             "mesh": [d.id for d in mesh.devices.flat]}
+            for (r, s), mesh in engine.meshes.items()]}
+
     losses = []
     reconfigs = []
+    placements = [placement(start)]
     for it in range(start, args.steps):
         now = float(it)
         from repro.core.detector.detector import FailureReport
@@ -200,6 +238,7 @@ def run_pipeline(cfg, args):
                 print(f"[recover] restored checkpoint step {step0} (Fig. 8b)")
             engine.apply_plan(adaptation.plan)
             reconfigs.append(it)
+            placements.append(placement(it))
 
         batch = {k: jnp.asarray(v) for k, v in ds.batch_at(it).items()}
         t0 = time.perf_counter()
@@ -213,7 +252,7 @@ def run_pipeline(cfg, args):
         if it % max(args.steps // 10, 1) == 0 or it == args.steps - 1:
             print(f"[train] step {it} loss {loss:.4f} {dt*1e3:.0f} ms "
                   f"plan={engine.plan.summary()}")
-    return {"losses": losses, "reconfigs": reconfigs}
+    return {"losses": losses, "reconfigs": reconfigs, "placements": placements}
 
 
 def main(argv=None):
@@ -240,6 +279,7 @@ def main(argv=None):
                     help="step:device@factor[,...]")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -72,7 +72,11 @@ def _sdpa_flash_chunked(q, k, v, seg_q, seg_k, pos_q, pos_k, *, causal, window, 
             mask = _mask(seg_q, sc, pos_q, pc, causal=causal, window=window)
             s = jnp.einsum("bqhd,bkhd->bhqk", q, kc).astype(jnp.float32) * scale
             s = jnp.where(mask[:, None], s, NEG_INF)
-            m_new = jnp.maximum(m, s.max(axis=-1))
+            # the output does not depend on the running max, so it takes no
+            # gradient: differentiating the max divides by a count of ties,
+            # which is 0/0 on TPU when XLA recomputes s with bf16 rounding
+            # that differs from the forward's
+            m_new = jax.lax.stop_gradient(jnp.maximum(m, s.max(axis=-1)))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new[..., None])
             l_new = l * alpha + p.sum(axis=-1)

@@ -37,7 +37,7 @@ def test_kernel_matches_ref(rng, S, H, K, dh, bq, bk, dtype):
     seg, pos = make_packed(rng, B, S)
     seg, pos = jnp.asarray(seg), jnp.asarray(pos)
     out = packed_attention(q, k, v, seg, seg, pos, pos, causal=True,
-                           block_q=bq, block_k=bk, interpret=True)
+                           block_q=bq, block_k=bk)
     ref = packed_attention_ref(q, k, v, seg, seg, pos, pos, causal=True)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
@@ -51,7 +51,7 @@ def test_kernel_window(rng, window):
     seg, pos = make_packed(rng, B, S, doc_lens=[S])
     seg, pos = jnp.asarray(seg), jnp.asarray(pos)
     out = packed_attention(q, k, v, seg, seg, pos, pos, causal=True,
-                           window=window, block_q=32, block_k=32, interpret=True)
+                           window=window, block_q=32, block_k=32)
     ref = packed_attention_ref(q, k, v, seg, seg, pos, pos, causal=True,
                                window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
@@ -66,7 +66,7 @@ def test_kernel_padding_rows_zero(rng):
     pos = np.arange(S, dtype=np.int32)[None] * (seg > 0)
     out = packed_attention(q, k, v, jnp.asarray(seg), jnp.asarray(seg),
                            jnp.asarray(pos), jnp.asarray(pos),
-                           causal=True, block_q=32, block_k=32, interpret=True)
+                           causal=True, block_q=32, block_k=32)
     assert bool(jnp.all(out[:, 40:] == 0))
 
 
@@ -85,7 +85,7 @@ def test_kernel_property_random_packing(doc_split, hk):
     seg, pos = make_packed(rng, 1, S, doc_lens=doc_split)
     seg, pos = jnp.asarray(seg), jnp.asarray(pos)
     out = packed_attention(q, k, v, seg, seg, pos, pos, causal=True,
-                           block_q=32, block_k=32, interpret=True)
+                           block_q=32, block_k=32)
     ref = packed_attention_ref(q, k, v, seg, seg, pos, pos, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5, rtol=3e-5)
 

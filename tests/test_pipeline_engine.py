@@ -3,6 +3,7 @@ fault injection -> reconfigure -> resume (loss continuity), migration
 identity, and checkpoint-restart determinism."""
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,7 @@ from repro.train.optimizer import make_optimizer
 pytestmark = pytest.mark.slow
 
 CFG = reduced(get_arch("qwen3-8b"), n_layers=4)
+REPO = Path(__file__).resolve().parents[1]
 
 
 def _batch(i=0, B=8, S=64):
@@ -101,7 +103,7 @@ def test_fault_tolerant_training_subprocess_8dev():
 
     full_env = dict(os.environ)
     full_env.update(env)
-    proc = subprocess.run([sys.executable, "-c", code], cwd="/root/repo",
+    proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
                           env=full_env, capture_output=True, text=True,
                           timeout=900)
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -125,7 +127,7 @@ def test_checkpoint_restart_determinism(tmp_path):
         )
         env = dict(os.environ)
         env["PYTHONPATH"] = "src"
-        p = subprocess.run([sys.executable, "-c", code], cwd="/root/repo",
+        p = subprocess.run([sys.executable, "-c", code], cwd=REPO,
                            env=env, capture_output=True, text=True, timeout=600)
         assert p.returncode == 0, p.stderr[-2000:]
         return float(p.stdout.strip().split("FINAL")[-1])
