@@ -131,7 +131,7 @@ def trainer_phase():
     cfg = dataclasses.replace(get_arch("qwen3-8b"), n_layers=1, vocab_size=QWEN3_VOCAB // 8)
     args = argparse.Namespace(
         seed=SEED, lr=3e-4, tp=1, microbatches=2, seq_len=SEQ, batch=2, steps=5,
-        ckpt_dir=None, ckpt_interval=10, resume=False)
+        ckpt_dir=None, ckpt_interval=10, resume=False, profile_dir=None)
     print(f"[trainer] qwen3-8b widths, {cfg.n_layers} layer, vocab {cfg.vocab_size} "
           f"(padded {cfg.padded_vocab}), {cfg.param_count() / 1e6:.1f}M params, "
           f"seq {args.seq_len}, batch {args.batch} in {args.microbatches} microbatches")
@@ -176,7 +176,7 @@ def pipeline_phase():
     args = argparse.Namespace(
         dp=1, pp=2, tp=2, microbatches=2, seq_len=SEQ, batch=2, steps=5, lr=3e-4,
         seed=SEED, ckpt_dir=None, ckpt_interval=10, resume=False,
-        inject_failstop="3:1", inject_failslow=None)
+        inject_failstop="3:1", inject_failslow=None, profile_dir=None)
     print(f"[pipeline] qwen3-8b widths, {cfg.n_layers} layers, vocab {cfg.vocab_size} "
           f"(padded {cfg.padded_vocab}), {cfg.param_count() / 1e6:.1f}M params, "
           f"dp1 pp2 tp2, seq {args.seq_len}, batch {args.batch}, fail-stop {args.inject_failstop}")

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.scheduler.plan import ParallelPlan
 from repro.engine.schedules import make_schedule
 from repro.launch.mesh import make_stage_mesh
@@ -97,24 +98,25 @@ class PipelineEngine:
     def apply_plan(self, plan: ParallelPlan):
         """(Re)build meshes + per-stage placements for a plan — the JAX
         analogue of 'destroy and rebuild communication groups'."""
-        self.plan = plan
-        self.meshes, self.policies = {}, {}
-        self._jit_cache = {}  # stage fns close over plan/policies: invalidate
-        for r, rep in enumerate(plan.replicas):
-            for s, st in enumerate(rep.stages):
-                if not st.devices:
-                    continue
-                mesh = self._mesh_for(st)
-                self.meshes[(r, s)] = mesh
-                pol = policy_for_mesh(mesh, shard_batch=False)
-                tp = pol.tp
-                if tp and self.cfg.n_heads % tp == 0:
-                    pol = pol.replace(attn_shard="heads")
-                elif tp and self.cfg.head_dim % tp == 0:
-                    pol = pol.replace(attn_shard="head_dim")
-                else:
-                    pol = pol.replace(attn_shard=None)
-                self.policies[(r, s)] = pol
+        with tracing.span("apply_plan"):
+            self.plan = plan
+            self.meshes, self.policies = {}, {}
+            self._jit_cache = {}  # stage fns close over plan/policies: invalidate
+            for r, rep in enumerate(plan.replicas):
+                for s, st in enumerate(rep.stages):
+                    if not st.devices:
+                        continue
+                    mesh = self._mesh_for(st)
+                    self.meshes[(r, s)] = mesh
+                    pol = policy_for_mesh(mesh, shard_batch=False)
+                    tp = pol.tp
+                    if tp and self.cfg.n_heads % tp == 0:
+                        pol = pol.replace(attn_shard="heads")
+                    elif tp and self.cfg.head_dim % tp == 0:
+                        pol = pol.replace(attn_shard="head_dim")
+                    else:
+                        pol = pol.replace(attn_shard=None)
+                    self.policies[(r, s)] = pol
 
     def stage_params(self, r: int, s: int):
         """Stage layer params + (first/last extras), placed on the stage mesh."""
@@ -164,17 +166,18 @@ class PipelineEngine:
             spec = cfg.layer_spec(l)
             x, _ = apply_layer(cfg, spec, p["layers"][i], x, md, pol)
         if s == self.plan.replicas[r].pp - 1:
-            x = rms_norm(x, p["final_norm"], cfg.norm_eps)
-            logits = lm_logits(cfg, p, x, pol)
-            return _mb_loss(cfg, logits, labels)
+            with tracing.scope("lm_head"):
+                x = rms_norm(x, p["final_norm"], cfg.norm_eps)
+                logits = lm_logits(cfg, p, x, pol)
+                return _mb_loss(cfg, logits, labels)
         return x
 
-    # one forward and one forward+vjp per (replica, stage); jit-cached
-    def _get_fns(self, r, s):
-        key = (r, s)
-        if not hasattr(self, "_jit_cache"):
-            self._jit_cache = {}
-        if key not in self._jit_cache:
+    def _stage_fn(self, kind, r, s):
+        """The jitted forward ("F") or forward+vjp ("B") of stage s on replica
+        r's mesh, and whether this call built it (its first call compiles)."""
+        key = (kind, r, s)
+        built = key not in self._jit_cache
+        if built:
             def fwd(p, x, md, tokens, labels):
                 return self._stage_apply(r, s, p, x, md, tokens=tokens, labels=labels)
 
@@ -185,14 +188,8 @@ class PipelineEngine:
                     p, x)
                 return vjp(g)
 
-            self._jit_cache[key] = (jax.jit(fwd), jax.jit(bwd))
-        return self._jit_cache[key]
-
-    def _fwd(self, r, s, p, x, md, tokens=None, labels=None):
-        return self._get_fns(r, s)[0](p, x, md, tokens, labels)
-
-    def _bwd(self, r, s, p, x, md, g, tokens=None, labels=None):
-        return self._get_fns(r, s)[1](p, x, md, g, tokens, labels)
+            self._jit_cache[key] = jax.jit(fwd if kind == "F" else bwd)
+        return self._jit_cache[key], built
 
     # -------------------------------------------------------- interpreter
     def run_iteration(self, batch, *, placement: Optional[dict] = None):
@@ -202,8 +199,11 @@ class PipelineEngine:
         placement: optional {ChunkId -> (replica, stage)} micro-batch
         migration overrides from the Scheduler (Fig. 6b).
         """
+        with tracing.step(self.step):
+            return self._iterate(batch, placement or {})
+
+    def _iterate(self, batch, placement):
         cfg, plan = self.cfg, self.plan
-        placement = placement or {}
         dp, pp, n_mb = plan.dp, plan.replicas[0].pp, plan.microbatches
         B = batch["tokens"].shape[0]
         assert B % (dp * n_mb) == 0, (B, dp, n_mb)
@@ -214,9 +214,11 @@ class PipelineEngine:
             return {k: v[lo: lo + mb_size] for k, v in batch.items()}
 
         params = {}
-        for r in range(dp):
-            for s in range(pp):
-                params[(r, s)], _ = self.stage_params(r, s)
+        with tracing.span("stage_params") as placed:
+            for r in range(dp):
+                for s in range(pp):
+                    params[(r, s)], _ = self.stage_params(r, s)
+            placed.set_metadata(bytes=tracing.nbytes(params))
 
         acts: dict = {}  # (r, m, s) -> boundary activation into stage s
         grads_in: dict = {}  # (r, m, s) -> gradient flowing into stage s's output
@@ -251,9 +253,10 @@ class PipelineEngine:
                     x_in = acts.get((r, m, s))
                     if s == 0:
                         x_in = jnp.zeros((mb_size, 1), jnp.float32)  # unused
-                    out = self._fwd(exec_rs[0], s, p, x_in, md,
-                                    tokens=mb["tokens"] if s == 0 else None,
-                                    labels=mb["labels"] if s == pp - 1 else None)
+                    fwd, built = self._stage_fn("F", exec_rs[0], s)
+                    with tracing.span("F", replica=r, stage=s, mb=m, built=int(built)):
+                        out = fwd(p, x_in, md, mb["tokens"] if s == 0 else None,
+                                  mb["labels"] if s == pp - 1 else None)
                     if s == pp - 1:
                         losses.append(out)  # (nll_sum, n_tokens)
                         grads_in[(r, m, s)] = (
@@ -264,8 +267,9 @@ class PipelineEngine:
                             type(cid)("F", m, s + 1, r), nxt))
                         y = out
                         if tgt_pol is not None and tgt_pol.mesh is not None:
-                            y = jax.device_put(
-                                y, tgt_pol.sharding_for(("batch", "seq", None), y.shape))
+                            with tracing.span("send", bytes=tracing.nbytes(y)):
+                                y = jax.device_put(
+                                    y, tgt_pol.sharding_for(("batch", "seq", None), y.shape))
                         acts[(r, m, s + 1)] = y  # SendAct -> RecvAct
                     done.add(cid)
                     q.pop(0)
@@ -278,10 +282,10 @@ class PipelineEngine:
                     if s == 0:
                         x_in = jnp.zeros((mb_size, 1), jnp.float32)
                     g = grads_in.pop((r, m, s))
-                    p_grad, x_grad = self._bwd(
-                        exec_rs[0], s, p, x_in, md, g,
-                        tokens=mb["tokens"] if s == 0 else None,
-                        labels=mb["labels"] if s == pp - 1 else None)
+                    bwd, built = self._stage_fn("B", exec_rs[0], s)
+                    with tracing.span("B", replica=r, stage=s, mb=m, built=int(built)):
+                        p_grad, x_grad = bwd(p, x_in, md, g, mb["tokens"] if s == 0 else None,
+                                             mb["labels"] if s == pp - 1 else None)
                     key = (r, s)
                     if key not in grad_acc:
                         grad_acc[key] = p_grad
@@ -289,9 +293,10 @@ class PipelineEngine:
                         grad_acc[key] = jax.tree.map(jnp.add, grad_acc[key], p_grad)
                     if s > 0:
                         prev_pol = self.policies[(r, s - 1)]
-                        gx = jax.device_put(
-                            x_grad,
-                            prev_pol.sharding_for(("batch", "seq", None), x_grad.shape))
+                        with tracing.span("send", bytes=tracing.nbytes(x_grad)):
+                            gx = jax.device_put(
+                                x_grad,
+                                prev_pol.sharding_for(("batch", "seq", None), x_grad.shape))
                         grads_in[(r, m, s - 1)] = gx
                     acts.pop((r, m, s), None)
                     done.add(cid)
@@ -302,8 +307,9 @@ class PipelineEngine:
                     q.pop(0)
                     progress = True
 
-        nll_total = sum(float(l[0]) for l in losses)
-        ntok_total = sum(float(l[1]) for l in losses)
+        with tracing.span("loss_sync"):
+            nll_total = sum(float(l[0]) for l in losses)
+            ntok_total = sum(float(l[1]) for l in losses)
         loss = nll_total / max(ntok_total, 1.0)
         self._apply_grads(grad_acc, ntok_total)
         return float(loss), grad_acc
@@ -313,30 +319,31 @@ class PipelineEngine:
         """DP-reduce per-stage grads, scatter into the full tree, update."""
         if self.optimizer is None:
             return
-        cfg, plan = self.cfg, self.plan
-        dp, pp = plan.dp, plan.replicas[0].pp
-        full_grads = jax.tree.map(jnp.zeros_like, self.params_full)
-        for s in range(pp):
-            st = plan.replicas[0].stages[s]
-            reduced = None
-            for r in range(dp):
-                g = grad_acc.get((r, s))
-                if g is None:
+        with tracing.span("apply_grads", bytes=tracing.nbytes(grad_acc)):
+            cfg, plan = self.cfg, self.plan
+            dp, pp = plan.dp, plan.replicas[0].pp
+            full_grads = jax.tree.map(jnp.zeros_like, self.params_full)
+            for s in range(pp):
+                st = plan.replicas[0].stages[s]
+                reduced = None
+                for r in range(dp):
+                    g = grad_acc.get((r, s))
+                    if g is None:
+                        continue
+                    g = jax.device_get(g)
+                    reduced = g if reduced is None else jax.tree.map(np.add, reduced, g)
+                if reduced is None:
                     continue
-                g = jax.device_get(g)
-                reduced = g if reduced is None else jax.tree.map(np.add, reduced, g)
-            if reduced is None:
-                continue
-            scale = 1.0 / max(total_tokens, 1.0)
-            reduced = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32) * scale, reduced)
-            for i, l in enumerate(st.layers):
-                full_grads["layers"][l] = reduced["layers"][i]
-            if s == 0:
-                full_grads["embed"] = reduced["embed"]
-            if s == pp - 1:
-                full_grads["final_norm"] = reduced["final_norm"]
-                if "lm_head" in reduced:
-                    full_grads["lm_head"] = reduced["lm_head"]
-        self.params_full, self.opt_state = self._update(
-            full_grads, self.opt_state, self.params_full, jnp.asarray(self.step))
-        self.step += 1
+                scale = 1.0 / max(total_tokens, 1.0)
+                reduced = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32) * scale, reduced)
+                for i, l in enumerate(st.layers):
+                    full_grads["layers"][l] = reduced["layers"][i]
+                if s == 0:
+                    full_grads["embed"] = reduced["embed"]
+                if s == pp - 1:
+                    full_grads["final_norm"] = reduced["final_norm"]
+                    if "lm_head" in reduced:
+                        full_grads["lm_head"] = reduced["lm_head"]
+            self.params_full, self.opt_state = self._update(
+                full_grads, self.opt_state, self.params_full, jnp.asarray(self.step))
+            self.step += 1

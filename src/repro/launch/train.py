@@ -35,8 +35,8 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from repro import tracing
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_arch, reduced as reduce_cfg
 from repro.core.detector.changepoint import CusumDetector
@@ -119,32 +119,47 @@ def run_spmd(cfg, args):
         changepoint_factory=lambda: CusumDetector(warmup=8),
     )
     losses, times, compile_s = [], [], None
-    for it in range(start, args.steps):
-        batch = {k: jnp.asarray(v) for k, v in ds.batch_at(it).items()}
-        if compile_s is None:
-            t0 = time.perf_counter()
-            step_fn = step_fn.lower(state, batch).compile()
-            compile_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        state, metrics = step_fn(state, batch)
-        jax.block_until_ready(state)
-        dt = time.perf_counter() - t0
-        loss = float(metrics["loss"])
-        stats = pack_stats(np.asarray(batch["segment_ids"]))
-        n, l2 = sum(s[0] for s in stats), sum(s[1] for s in stats)
-        if it - start >= 2:  # skip warm-up iterations
-            pred.observe(n, l2, dt)
-            if len(pred._obs) >= 4 and not pred.fitted:
-                pred.fit()
-            detector.observe_iteration(it, dt, (n, l2))
-        losses.append(loss)
-        times.append(dt)
-        if ckpt:
-            ckpt.maybe_save(state, it + 1, extra={"loss": loss})
-        if it % max(args.steps // 10, 1) == 0 or it == args.steps - 1:
-            print(f"[train] step {it} loss {loss:.4f} {dt*1e3:.0f} ms")
+    with tracing.profile(args.profile_dir) as capture:
+        for it in range(start, args.steps):
+            capture(it - start)
+            with tracing.step(it):
+                host = ds.batch_at(it)
+                with tracing.span("batch", bytes=tracing.nbytes(host)):
+                    batch = {k: jnp.asarray(v) for k, v in host.items()}
+                if compile_s is None:
+                    t0 = time.perf_counter()
+                    step_fn = step_fn.lower(state, batch).compile()
+                    compile_s = time.perf_counter() - t0
+                t0 = time.perf_counter()
+                with tracing.span("dispatch"):
+                    state, metrics = step_fn(state, batch)
+                with tracing.span("wait_state"):
+                    jax.block_until_ready(state)
+                dt = time.perf_counter() - t0
+                loss = float(metrics["loss"])
+                with tracing.span("detect"):
+                    stats = pack_stats(host["segment_ids"])
+                    n, l2 = sum(s[0] for s in stats), sum(s[1] for s in stats)
+                    if it - start >= 2:  # skip warm-up iterations
+                        pred.observe(n, l2, dt)
+                        if len(pred._obs) >= 4 and not pred.fitted:
+                            pred.fit()
+                        detector.observe_iteration(it, dt, (n, l2))
+                losses.append(loss)
+                times.append(dt)
+                if ckpt:
+                    _checkpoint(ckpt, state, it + 1, loss)
+            if it % max(args.steps // 10, 1) == 0 or it == args.steps - 1:
+                print(f"[train] step {it} loss {loss:.4f} {dt*1e3:.0f} ms")
     return {"losses": losses, "times": times, "compile_s": compile_s,
             "detector": detector.stats.as_dict()}
+
+
+def _checkpoint(ckpt, state, step, loss):
+    """Save every `ckpt.interval` steps, under the span `checkpoint`."""
+    with tracing.span("checkpoint") as saved:
+        if ckpt.maybe_save(state, step, extra={"loss": loss}):
+            saved.set_metadata(bytes=tracing.nbytes(state))
 
 
 def spmd_step(cfg, policy, opt, args):
@@ -203,55 +218,57 @@ def run_pipeline(cfg, args):
     losses = []
     reconfigs = []
     placements = [placement(start)]
-    for it in range(start, args.steps):
-        now = float(it)
-        from repro.core.detector.detector import FailureReport
+    with tracing.profile(args.profile_dir) as capture:
+        for it in range(start, args.steps):
+            capture(it - start)
+            now = float(it)
+            from repro.core.detector.detector import FailureReport
 
-        if it in injections:
-            dev = injections[it]
-            print(f"[inject] fail-stop device {dev} at step {it}")
-            controller.speeds[dev] = 0.0
-            controller.pending.append(FailureReport("fail-stop", (dev,), it, now))
-        if it in slow_inj:
-            dev, f = slow_inj[it]
-            print(f"[inject] fail-slow device {dev} -> {f} at step {it}")
-            controller.speeds[dev] = f
-            controller.pending.append(FailureReport("fail-slow", ((dev, f),), it, now))
+            if it in injections:
+                dev = injections[it]
+                print(f"[inject] fail-stop device {dev} at step {it}")
+                controller.speeds[dev] = 0.0
+                controller.pending.append(FailureReport("fail-stop", (dev,), it, now))
+            if it in slow_inj:
+                dev, f = slow_inj[it]
+                print(f"[inject] fail-slow device {dev} -> {f} at step {it}")
+                controller.speeds[dev] = f
+                controller.pending.append(FailureReport("fail-slow", ((dev, f),), it, now))
 
-        adaptation = controller.adapt(now)
-        if adaptation is not None:
-            old_plan = engine.plan
-            print(f"[adapt] {adaptation.plan.summary()}")
-            for note in adaptation.notes:
-                print(f"        {note}")
-            tp_ = transfer_plan(cfg, old_plan, adaptation.plan,
-                                dead_stages=adaptation.dead_stages)
-            print(f"[recover] {len(tp_.moves)} layer moves, "
-                  f"{tp_.total_bytes/1e6:.1f} MB, est {tp_.seconds():.2f}s on IB")
-            if tp_.restore_required:
-                if ckpt is None or not ckpt.has_checkpoint():
-                    raise RuntimeError("stage lost all replicas and no checkpoint")
-                full, step0, _ = ckpt.restore_latest(
-                    target={"params": engine.params_full, "opt": engine.opt_state,
-                            "step": engine.step})
-                engine.params_full, engine.opt_state = full["params"], full["opt"]
-                print(f"[recover] restored checkpoint step {step0} (Fig. 8b)")
-            engine.apply_plan(adaptation.plan)
-            reconfigs.append(it)
-            placements.append(placement(it))
+            adaptation = controller.adapt(now)
+            if adaptation is not None:
+                old_plan = engine.plan
+                print(f"[adapt] {adaptation.plan.summary()}")
+                for note in adaptation.notes:
+                    print(f"        {note}")
+                tp_ = transfer_plan(cfg, old_plan, adaptation.plan,
+                                    dead_stages=adaptation.dead_stages)
+                print(f"[recover] {len(tp_.moves)} layer moves, "
+                      f"{tp_.total_bytes/1e6:.1f} MB, est {tp_.seconds():.2f}s on IB")
+                if tp_.restore_required:
+                    if ckpt is None or not ckpt.has_checkpoint():
+                        raise RuntimeError("stage lost all replicas and no checkpoint")
+                    full, step0, _ = ckpt.restore_latest(
+                        target={"params": engine.params_full, "opt": engine.opt_state,
+                                "step": engine.step})
+                    engine.params_full, engine.opt_state = full["params"], full["opt"]
+                    print(f"[recover] restored checkpoint step {step0} (Fig. 8b)")
+                engine.apply_plan(adaptation.plan)
+                reconfigs.append(it)
+                placements.append(placement(it))
 
-        batch = {k: jnp.asarray(v) for k, v in ds.batch_at(it).items()}
-        t0 = time.perf_counter()
-        loss, _ = engine.run_iteration(batch)
-        dt = time.perf_counter() - t0
-        losses.append(loss)
-        if ckpt:
-            ckpt.maybe_save(
-                {"params": engine.params_full, "opt": engine.opt_state,
-                 "step": engine.step}, it + 1, extra={"loss": loss})
-        if it % max(args.steps // 10, 1) == 0 or it == args.steps - 1:
-            print(f"[train] step {it} loss {loss:.4f} {dt*1e3:.0f} ms "
-                  f"plan={engine.plan.summary()}")
+            batch = {k: jnp.asarray(v) for k, v in ds.batch_at(it).items()}
+            t0 = time.perf_counter()
+            loss, _ = engine.run_iteration(batch)
+            dt = time.perf_counter() - t0
+            losses.append(loss)
+            if ckpt:
+                ckpt.maybe_save(
+                    {"params": engine.params_full, "opt": engine.opt_state,
+                     "step": engine.step}, it + 1, extra={"loss": loss})
+            if it % max(args.steps // 10, 1) == 0 or it == args.steps - 1:
+                print(f"[train] step {it} loss {loss:.4f} {dt*1e3:.0f} ms "
+                      f"plan={engine.plan.summary()}")
     return {"losses": losses, "reconfigs": reconfigs, "placements": placements}
 
 
@@ -278,6 +295,9 @@ def main(argv=None):
     ap.add_argument("--inject-failslow", default=None,
                     help="step:device@factor[,...]")
     ap.add_argument("--out", default=None)
+    ap.add_argument("--profile-dir", default=None,
+                    help="capture a profile of the steps after the first two into this "
+                         "directory (XProf / TensorBoard); spans in repro.tracing")
     args = ap.parse_args(argv)
     use_compile_cache()
 

@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.models.layers import dense_init
 from repro.parallel.sharding import annotate
 
@@ -19,8 +20,9 @@ def init_mlp(key, cfg):
 
 
 def mlp(cfg, p, x, policy):
-    h = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(x.dtype))
-    u = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(x.dtype))
-    h = jax.nn.silu(h) * u
-    h = policy.constrain(h, "batch", "seq", "ffn")
-    return jnp.einsum("bsf,fd->bsd", h, p["w_down"].astype(x.dtype))
+    with tracing.scope("mlp"):
+        h = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(x.dtype))
+        u = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(x.dtype))
+        h = jax.nn.silu(h) * u
+        h = policy.constrain(h, "batch", "seq", "ffn")
+        return jnp.einsum("bsf,fd->bsd", h, p["w_down"].astype(x.dtype))

@@ -14,6 +14,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.models import attention as attn_mod
 from repro.models.attention import attention, init_attention, precompute_cross_kv
 from repro.models.layers import norm_param, rms_norm
@@ -265,8 +266,9 @@ def forward_train(cfg, params, batch, policy, *, use_scan=True, remat=True,
     x, caches = _run_layers(cfg, params["layers"], x, md, policy, use_scan=use_scan, remat=remat)
     if _collect is not None:
         _collect["caches"] = caches
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_logits(cfg, params, x, policy)
+    with tracing.scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = lm_logits(cfg, params, x, policy)
 
     if cfg.n_experts:  # load-balance aux from a replicated router pass (cheap)
         moe_layers = [p for pos, p in enumerate(params["layers"]) if cfg.period[pos].ffn == "moe"]
@@ -278,16 +280,17 @@ def forward_train(cfg, params, batch, policy, *, use_scan=True, remat=True,
 
 def loss_fn(cfg, params, batch, policy, **fw_kwargs):
     logits, aux = forward_train(cfg, params, batch, policy, **fw_kwargs)
-    labels = batch["labels"]
-    mask = (labels >= 0).astype(jnp.float32)
-    labels_c = jnp.maximum(labels, 0)
-    logits = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    ll = jnp.take_along_axis(logits, labels_c[..., None], axis=-1)[..., 0]
-    nll = (lse - ll) * mask
-    denom = jnp.maximum(mask.sum(), 1.0)
-    loss = nll.sum() / denom
-    zloss = 1e-4 * jnp.sum(jnp.square(lse) * mask) / denom
+    with tracing.scope("lm_head"):
+        labels = batch["labels"]
+        mask = (labels >= 0).astype(jnp.float32)
+        labels_c = jnp.maximum(labels, 0)
+        logits = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        ll = jnp.take_along_axis(logits, labels_c[..., None], axis=-1)[..., 0]
+        nll = (lse - ll) * mask
+        denom = jnp.maximum(mask.sum(), 1.0)
+        loss = nll.sum() / denom
+        zloss = 1e-4 * jnp.sum(jnp.square(lse) * mask) / denom
     total = loss + zloss + 0.01 * aux["moe_aux"]
     return total, {"loss": loss, "zloss": zloss, "moe_aux": aux["moe_aux"], "ntokens": mask.sum()}
 
@@ -377,6 +380,7 @@ def serve_forward(cfg, params, cache, batch, policy, compute_dtype=jnp.bfloat16)
     x = embed_tokens(cfg, params, tokens, compute_dtype)
     x = policy.constrain(x, "batch", None, None)
     x, new_cache = _run_layers(cfg, params["layers"], x, md, policy, caches=cache, use_scan=True)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_logits(cfg, params, x, policy)
+    with tracing.scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = lm_logits(cfg, params, x, policy)
     return logits, new_cache

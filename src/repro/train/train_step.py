@@ -14,6 +14,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.models.model import (
     forward_train,
     loss_fn,
@@ -125,10 +126,11 @@ def build_train_step(cfg, policy: ShardingPolicy, optimizer, *, microbatches=1,
 
         zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, accum_dtype), params)
         (grads, loss_sum), metrics = jax.lax.scan(accum, (zeros, jnp.zeros((), jnp.float32)), mbs)
-        grads = jax.tree.map(lambda g: (g / microbatches).astype(jnp.float32), grads)
-        gnorm = global_norm(grads)
-        scale = jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-9))
-        grads = jax.tree.map(lambda g: g * scale, grads)
+        with tracing.scope("optimizer"):
+            grads = jax.tree.map(lambda g: (g / microbatches).astype(jnp.float32), grads)
+            gnorm = global_norm(grads)
+            scale = jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-9))
+            grads = jax.tree.map(lambda g: g * scale, grads)
         new_params, new_opt = optimizer.update(grads, state["opt"], params, state["step"])
         new_state = {"params": new_params, "opt": new_opt, "step": state["step"] + 1}
         out_metrics = {
