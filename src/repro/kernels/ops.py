@@ -1,14 +1,17 @@
-"""Public jit'd wrapper over the Pallas packed flash attention kernel.
+"""Public jit'd wrapper over the Pallas packed flash attention kernels.
 
-Dispatch: on the CPU backend the kernel body runs in interpret mode (same
-kernel, Python evaluation), so correctness is validated end to end without a
-chip; on every other backend it is compiled natively.
+Dispatch: on the CPU backend the kernels run in interpret mode (same kernels,
+Python evaluation), so values and gradients are validated end to end without
+a chip; on every other backend they are compiled natively. The wrapper is
+differentiable in q, k and v through the kernels' own backward pass.
 """
 from __future__ import annotations
 
 import jax
 
 from repro.kernels.packed_flash_attn import (  # noqa: F401
+    BLOCK_K,
+    BLOCK_Q,
     block_metadata,
     packed_flash_attention,
     skipped_block_fraction,
@@ -17,7 +20,7 @@ from repro.kernels.ref import packed_attention_ref  # noqa: F401
 
 
 def packed_attention(q, k, v, seg_q, seg_k, pos_q, pos_k, *, causal=True,
-                     window=None, scale=None, block_q=128, block_k=128):
+                     window=None, scale=None, block_q=BLOCK_Q, block_k=BLOCK_K):
     """Segment-aware flash attention; interpret mode on CPU only."""
     return packed_flash_attention(
         q, k, v, seg_q, seg_k, pos_q, pos_k,

@@ -51,6 +51,7 @@ from repro.core.scheduler.scheduler import Scheduler
 from repro.data.packing import pack_stats
 from repro.data.synth import SyntheticPackedDataset
 from repro.engine.pipeline import PipelineEngine
+from repro.kernels.packed_flash_attn import tile_counts
 from repro.parallel.sharding import NULL_POLICY, policy_for_mesh
 from repro.train.optimizer import optimizer_for
 from repro.train.train_step import build_train_step, init_train_state, sharding_for_state
@@ -137,7 +138,9 @@ def run_spmd(cfg, args):
                     jax.block_until_ready(state)
                 dt = time.perf_counter() - t0
                 loss = float(metrics["loss"])
-                with tracing.span("detect"):
+                with tracing.span("detect") as detect:
+                    tiles, tiles_run = tile_counts(host["segment_ids"])
+                    detect.set_metadata(attn_tiles=tiles, attn_tiles_run=tiles_run)
                     stats = pack_stats(host["segment_ids"])
                     n, l2 = sum(s[0] for s in stats), sum(s[1] for s in stats)
                     if it - start >= 2:  # skip warm-up iterations

@@ -1,9 +1,12 @@
-"""GQA attention: packed-segment masks, SWA, qk-norm, M-RoPE, flash-chunked
-training path, KV-cache decode path.
+"""GQA attention: packed-segment masks, SWA, qk-norm, M-RoPE, KV-cache decode.
 
-The jnp flash-chunked path (lax.scan over KV chunks with running max/sum) is
-the lowering reference; `repro.kernels.packed_flash_attn` is the Pallas TPU
-kernel with the same semantics (and block skipping on the segment mask).
+The core of a call with no KV cache (training, prefill) is the Pallas packed
+flash attention (`repro.kernels.packed_flash_attn`) when the program is
+lowered for a TPU and no mesh axis splits its inputs: it skips the tiles the
+packing mask rules out, forward and backward. Everywhere else (the CPU, a
+head-sharded stage, decode) the jnp paths with the same semantics compute
+it: dense masked softmax up to 2 * flash_chunk keys, else a lax.scan over KV
+chunks with running max/sum.
 """
 from __future__ import annotations
 
@@ -14,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import tracing
+from repro.kernels.packed_flash_attn import BLOCK_K, BLOCK_Q, packed_flash_attention
 from repro.models.layers import apply_rope, dense_init, head_rms_norm, rope_angles
 from repro.parallel.sharding import annotate
 
@@ -43,6 +47,12 @@ def _mask(seg_q, seg_k, pos_q, pos_k, *, causal, window):
     if window is not None:
         same &= (pos_q[:, :, None] - pos_k[:, None, :]) < window
     return same
+
+
+def _splits(policy, axes, shape):
+    """Whether the policy lays an array of these logical axes over more than
+    one device."""
+    return any(policy.axis_size(a) > 1 for a in policy.spec_for(axes, shape))
 
 
 def _sdpa_dense(q, k, v, mask, scale):
@@ -155,43 +165,50 @@ def attention(cfg, spec, p, x, md, policy, cache=None):
                 pos_k = jnp.maximum(pos_arr, 0)
                 seg_k = (pos_arr >= 0).astype(jnp.int32)  # valid cache entries
 
-        # expand KV heads to H query heads (GQA)
-        if k_all.shape[2] != H:
-            rep = H // k_all.shape[2]
-            k_all = jnp.repeat(k_all, rep, axis=2)
-            v_all = jnp.repeat(v_all, rep, axis=2)
-
-        with tracing.scope("attn_core"):  # whichever path computes it
-            if cache is not None:
-                # decode path: queries are length-1 (or small); dense masked attention
-                pos_q = md["lengths"][:, None] + jnp.arange(S)[None]
-                seg_q = jnp.ones((B, S), jnp.int32)
-                mask = _mask(seg_q, seg_k, pos_q, pos_k, causal=causal, window=window)
-                out = _sdpa_dense(q, k_all, v_all, mask, scale)
-            else:
-                pos_q = md["abs_positions"] if kx is None else md["abs_positions"]
-                seg_q = md["segment_ids"]
-                Sk = k_all.shape[1]
-                chunk = md.get("flash_chunk", 1024)
-                if md.get("use_pallas_kernel"):
-                    # Pallas packed flash attention (block-skipping on the packing
-                    # mask): native on TPU, interpret mode elsewhere.
-                    from repro.kernels.ops import packed_attention
-
-                    out = packed_attention(
-                        q, k_all, v_all, seg_q, seg_k, pos_q, pos_k,
-                        causal=causal, window=window, scale=scale,
-                        block_q=md.get("kernel_block_q", 128),
-                        block_k=md.get("kernel_block_k", 128),
-                    )
-                elif Sk <= 2 * chunk:
+        def jnp_core(q, k_all, v_all, seg_q, seg_k, pos_q, pos_k):
+            # expand KV heads to H query heads (GQA)
+            if k_all.shape[2] != H:
+                rep = H // k_all.shape[2]
+                k_all = jnp.repeat(k_all, rep, axis=2)
+                v_all = jnp.repeat(v_all, rep, axis=2)
+            with tracing.scope("attn_core"):
+                if cache is not None:
+                    # decode path: queries are length-1 (or small); dense masked attention
                     mask = _mask(seg_q, seg_k, pos_q, pos_k, causal=causal, window=window)
-                    out = _sdpa_dense(q, k_all, v_all, mask, scale)
-                else:
-                    out = _sdpa_flash_chunked(
-                        q, k_all, v_all, seg_q, seg_k, pos_q, pos_k,
-                        causal=causal, window=window, scale=scale, chunk=chunk,
-                    )
+                    return _sdpa_dense(q, k_all, v_all, mask, scale)
+                chunk = md.get("flash_chunk", 1024)
+                if k_all.shape[1] <= 2 * chunk:
+                    mask = _mask(seg_q, seg_k, pos_q, pos_k, causal=causal, window=window)
+                    return _sdpa_dense(q, k_all, v_all, mask, scale)
+                return _sdpa_flash_chunked(
+                    q, k_all, v_all, seg_q, seg_k, pos_q, pos_k,
+                    causal=causal, window=window, scale=scale, chunk=chunk,
+                )
+
+        def kernel_core(q, k_all, v_all, seg_q, seg_k, pos_q, pos_k, interpret=False):
+            # skips the tiles the packing mask rules out, takes K/V heads
+            # through its index maps, and trains through its own backward
+            with tracing.scope("attn_core"):
+                return packed_flash_attention(
+                    q, k_all, v_all, seg_q, seg_k, pos_q, pos_k,
+                    causal=causal, window=window, scale=scale,
+                    block_q=md.get("kernel_block_q", BLOCK_Q),
+                    block_k=md.get("kernel_block_k", BLOCK_K), interpret=interpret)
+
+        if cache is not None:
+            pos_q = md["lengths"][:, None] + jnp.arange(S)[None]
+            out = jnp_core(q, k_all, v_all, jnp.ones((B, S), jnp.int32), seg_k, pos_q, pos_k)
+        else:
+            args = (q, k_all, v_all, md["segment_ids"], seg_k, md["abs_positions"], pos_k)
+            if md.get("use_pallas_kernel"):  # forced, as CPU tests do: interpreted there
+                out = kernel_core(*args, interpret=jax.default_backend() == "cpu")
+            elif _splits(policy, ("batch", "seq", "heads", "head_dim"), q.shape) or _splits(
+                    policy, ("batch", "seq", "kv_heads", "head_dim"), k_all.shape):
+                out = jnp_core(*args)  # GSPMD does not partition a pallas_call
+            else:
+                # the kernel on TPU; chosen when the program is lowered, so a
+                # compile for a described chip from a CPU host takes it too
+                out = jax.lax.platform_dependent(*args, tpu=kernel_core, default=jnp_core)
 
         out = policy.constrain(out, "batch", "seq", "heads", "head_dim")
         y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))

@@ -27,7 +27,7 @@ import jax
 # device scopes, by the layer each names
 SCOPES = (
     "attn_core",  # attention's core: dense, chunked or Pallas (models/attention.py)
-    "attn_proj",  # the rest of attention: q/k/v/o, qk-norm, RoPE, GQA repeat
+    "attn_proj",  # the rest of attention: q/k/v/o, qk-norm, RoPE, the jnp paths' GQA repeat
     "mlp",  # the dense gated FFN (models/mlp.py)
     "lm_head",  # final norm, logits, log-softmax, z-loss and nll (models/model.py, engine/pipeline.py)
     "optimizer",  # gradient scale, global norm, clip and update (train/)
@@ -39,7 +39,9 @@ SPANS = (
     "batch",  # bytes: the batch sent to the device
     "dispatch",  # the jitted step's call
     "wait_state",  # block on the new state
-    "detect",  # pack_stats, the predictor and Detector.observe_iteration
+    "detect",  # pack_stats, the predictor and Detector.observe_iteration; attn_tiles: the
+    # (q, k) tiles of one causal attention call over the batch at the kernel's block
+    # sizes, attn_tiles_run: those its tile table lets run (host numpy, no device work)
     "checkpoint",  # bytes: the state saved, when it saves
     # engine/pipeline.PipelineEngine, inside each iteration
     "stage_params",  # bytes: parameters placed on the stage meshes

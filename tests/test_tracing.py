@@ -14,11 +14,13 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro import tracing
 from repro.configs import get_arch, reduced
 from repro.data.synth import SyntheticPackedDataset
+from repro.kernels.packed_flash_attn import BLOCK_K, BLOCK_Q, skipped_block_fraction
 from repro.launch.train import run_spmd, spmd_step
 from repro.models.attention import attention, init_attention
 from repro.parallel.sharding import NULL_POLICY, split_annotations
@@ -121,17 +123,30 @@ def _attention_inputs():
     return p, x, md
 
 
+PALLAS = {"use_pallas_kernel": True, "kernel_block_q": 16, "kernel_block_k": 16}
+
+
 @pytest.mark.parametrize("path, extra, marker", [
     ("dense", {"flash_chunk": 64}, "attn_proj/attn_core/bqhd,bkhd->bhqk/dot_general"),
     ("chunked", {"flash_chunk": 16}, "attn_proj/attn_core/while/body/"),
-    ("pallas", {"use_pallas_kernel": True, "kernel_block_q": 16, "kernel_block_k": 16},
-     "attn_proj/attn_core/jit(packed_flash_attention)/"),
+    ("pallas", PALLAS, "attn_proj/attn_core/jit(packed_flash_attention)/"),
+    ("pallas backward dq", PALLAS,
+     "transpose(jvp(attn_proj))/attn_core/jit(packed_flash_attention)/packed_attn_dq/"),
+    ("pallas backward dkv", PALLAS,
+     "transpose(jvp(attn_proj))/attn_core/jit(packed_flash_attention)/packed_attn_dkv/"),
 ])
 def test_attn_core_names_the_core_on_every_path(path, extra, marker):
     p, x, md = _attention_inputs()
     md = {**md, **extra}
-    names = _op_names(lambda p, x: attention(CFG, CFG.layer_spec(0), p, x, md, NULL_POLICY)[0],
-                      p, x)
+    layer = lambda p, x: attention(CFG, CFG.layer_spec(0), p, x, md, NULL_POLICY)[0]
+    if "backward" in path:  # the layer's vjp: forward, then both backward kernels
+        ct = jnp.ones(x.shape, x.dtype)
+        names = _op_names(lambda p, x, ct: jax.vjp(layer, p, x)[1](ct), p, x, ct)
+    else:
+        names = _op_names(layer, p, x)
+    # the switch on the platform, a cond of one branch per platform, puts
+    # its branch between the scopes on the paths it chooses between
+    names = [re.sub(r"cond/branch_\d+_fun/", "", n) for n in names]
     assert any(marker in n for n in names), path
     assert {tracing.scope_of(n) for n in names} == {"attn_proj", "attn_core"}
     projections = [n for n in names if re.search(r"/(bsd,dhk->bshk|bsd,dkh->bskh|bshk,hkd->bsd)/", n)]
@@ -181,6 +196,25 @@ def test_run_spmd_profile_holds_each_step_and_its_spans(tmp_path):
         stats = {e[0]: e[3] for e in spans}
         assert stats["batch"]["bytes"] == 4 * 4 * 32 * 4  # four int32 fields, (4, 32)
         assert stats["checkpoint"]["bytes"] > 0  # saved: interval 1
+
+
+def test_detect_span_counts_the_attention_tiles_the_kernel_runs(tmp_path):
+    """The `detect` span's attn_tiles / attn_tiles_run, from the host batch at
+    the kernel's block size: their ratio is 1 - skipped_block_fraction."""
+    args = argparse.Namespace(
+        lr=1e-3, tp=1, seed=0, microbatches=2, seq_len=1024, batch=2, steps=3,
+        ckpt_dir=None, ckpt_interval=1, resume=False, profile_dir=str(tmp_path / "profile"))
+    run_spmd(CFG, args)
+    steps = _by_step(_host_events(args.profile_dir))
+    assert sorted(steps) == [2]
+    stats, = [e[3] for e in steps[2] if e[0] == "detect"]
+    seg = SyntheticPackedDataset(CFG, args.seq_len, args.batch, seed=args.seed).batch_at(2)[
+        "segment_ids"]
+    pos = np.broadcast_to(np.arange(args.seq_len, dtype=np.int32), seg.shape)
+    assert stats["attn_tiles"] == args.batch * (args.seq_len // BLOCK_Q) * (args.seq_len // BLOCK_K)
+    skipped = skipped_block_fraction(seg, pos, BLOCK_Q, BLOCK_K)
+    assert 0 < skipped < 1
+    assert stats["attn_tiles_run"] / stats["attn_tiles"] == pytest.approx(1 - skipped, abs=1e-12)
 
 
 PIPELINE = textwrap.dedent("""
