@@ -1,15 +1,18 @@
 """How the program holds a qwen3 configuration and its parameters.
 
 `arch` builds the program's ArchConfig from the configuration file's
-Hugging Face keys. `to_program` and `from_program` map the reference's
-canonical leaves (reference/qwen3.py) to the program's parameter tree, in the
-SPMD train state's scan layout (one period position, layers stacked on axis
-0), and back. RMSNorm leaves are offsets from 1 on both sides.
+Hugging Face keys, and `work` counts what one training step needs.
+`to_program` and `from_program` map the reference's canonical leaves
+(reference/qwen3.py) to the program's parameter tree, in the SPMD train
+state's scan layout (one period position, layers stacked on axis 0), and
+back. RMSNorm leaves are offsets from 1 on both sides.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from chipbench import flops
 
 # canonical per-layer name -> path in the program's list-layout layer tree
 LAYER_PATHS = {
@@ -37,6 +40,18 @@ def arch(cfg):
         raise ValueError(f"program pads the vocabulary to {a.padded_vocab}, "
                          f"the configuration assumes {cfg['assumed']['padded_vocab_size']}")
     return a
+
+
+def work(cfg, segment_ids) -> dict:
+    """The step's model FLOPs (matmuls over document tokens plus attention)
+    and attention's FLOPs and bytes, for rows with these segment ids."""
+    D, F, H, K, dh, L = (cfg["hidden_size"], cfg["intermediate_size"],
+                         cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                         cfg["head_dim"], cfg["num_hidden_layers"])
+    V = cfg["assumed"]["padded_vocab_size"]
+    attn_flops, attn_bytes = flops.attention_work(segment_ids, H, K, dh, dh, L)
+    matmuls = 6 * flops.gqa_matmul_params(D, F, H, K, dh, dh, L, V) * flops.doc_tokens(segment_ids)
+    return {"flops": matmuls + attn_flops, "attn_flops": attn_flops, "attn_bytes": attn_bytes}
 
 
 def _layer(canon, i):

@@ -1,11 +1,8 @@
 """Device milliseconds per step of the operations under the program's
-`attn_core` scope (models/attention.py), over the traced window's steps."""
+`attn_core` scope (models/attention.py: the attention core, on TPU the
+Pallas kernels), over the traced window's steps."""
+from chipbench.layer_metrics import scope_ms
 
 
 def read(ctx):
-    if ctx.trace is None or not ctx.steps:
-        return None
-    seconds = ctx.trace["scope_s"].get("attn_core", 0.0)
-    if seconds <= 0:
-        return None
-    return 1e3 * seconds / len(ctx.steps)
+    return scope_ms(ctx, "attn_core")

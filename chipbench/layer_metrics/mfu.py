@@ -1,6 +1,6 @@
 """Model FLOP/s utilization of the whole step: the FLOPs the window's steps
-need (flops.step_flops: matmuls and causal per-document attention, forward
-and backward, no recomputation) over the window's host-clock time, the
+need (the arch module's `flops`: matmuls and causal per-document attention,
+forward and backward, no recomputation) over the window's host-clock time, the
 cell's chips and each chip's bf16 peak. Percent."""
 
 

@@ -5,7 +5,9 @@
 Everything is found by name from BENCHMARK.json: the cell's configuration
 (chipbench/configs/<config>.json), its traffic mix
 (chipbench/traffic/<mix>.json), the runner the configuration names
-(chipbench/runners/<runner>.py), and a reader per metric
+(chipbench/runners/<runner>.py), the arch module and the plain reference of
+its model type (chipbench/archs/<model_type>.py, whose `work` counts a step's
+work, and chipbench/reference/<model_type>.py), and a reader per metric
 (chipbench/end_to_end/<metric>.py, chipbench/layer_metrics/<metric>.py).
 
 A run loads, warms up (set-up), measures for --seconds, then checks what the
@@ -68,6 +70,9 @@ def resolve(name, root=ROOT) -> SimpleNamespace:
                 raise FileNotFoundError(f"no reader {kind}/{m['name']}.py")
     if not (base / "runners" / f"{cfg['runner']}.py").is_file():
         raise FileNotFoundError(f"no runner runners/{cfg['runner']}.py")
+    arch = importlib.import_module(f"chipbench.archs.{cfg['model_type']}")
+    if not callable(getattr(arch, "work", None)):
+        raise TypeError(f"archs/{cfg['model_type']}.py has no work(cfg, segment_ids)")
     return SimpleNamespace(workload=w, cfg=cfg, mix=mix, chips=w["chips"],
                            end_to_end=e2e, per_layer=layer)
 

@@ -9,12 +9,32 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import correctness, flops
+from chipbench import correctness
 from chipbench.traffic import Traffic
 
 def span(name):
     """A host span in the profiler's trace (cheap when no trace is taken)."""
     return jax.profiler.TraceAnnotation(name)
+
+
+def fresh_text(jitted, *args):
+    """The text of `jitted` compiled for `args` by this process from this
+    tree's code. JAX's compile caches leave `op_name` metadata out of their
+    keys, so a program read back from the persistent cache, or from this
+    process's own caches filled from it, carries the names of the tree that
+    compiled it first. The persistent cache is off for this one compile and
+    then as it was; the process's own caches are emptied."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    jax.clear_caches()
+    try:
+        return jitted.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
 
 
 def arch_module(cfg):
@@ -96,9 +116,8 @@ def check(cfg, seed, prog, batches):
 
 
 def step_work(cfg, batch):
-    """Per-step counts the metrics need: target tokens, model FLOPs and the
-    attention work the packing mask needs."""
-    seg = batch["segment_ids"]
-    attn_flops, attn_bytes = flops.attention_work(cfg, seg)
-    return {"tokens": int(np.sum(batch["labels"] >= 0)), "flops": flops.step_flops(cfg, seg),
-            "attn_flops": attn_flops, "attn_bytes": attn_bytes}
+    """Per-step counts the metrics need: target tokens, and what the
+    configuration's arch module counts (`work`: at least model FLOPs and the
+    attention work the packing mask needs)."""
+    return {"tokens": int(np.sum(batch["labels"] >= 0)),
+            **arch_module(cfg).work(cfg, batch["segment_ids"])}

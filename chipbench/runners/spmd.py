@@ -91,7 +91,7 @@ class Run:
         """The compiled step's text, whose op_name metadata names the
         scopes of the trace's operations."""
         batch = self._device_batch(self.pool[0])
-        return [self.step_fn.lower(self.state, batch).compile().as_text()]
+        return [common.fresh_text(self.step_fn, self.state, batch)]
 
     def free(self):
         self.state = self.step_fn = None

@@ -1,11 +1,13 @@
-"""The benchmark's own counts: FLOPs and bytes from shapes, the table of
-peaks, and the traffic generator."""
+"""The benchmark's own counts: FLOPs and bytes from shapes (the shared
+formulas and each arch module's `work`), the table of peaks, and the
+traffic generator."""
 import json
 
 import numpy as np
 import pytest
 
 from chipbench import device, flops
+from chipbench.archs import qwen3
 from chipbench.tests import tiny
 from chipbench.traffic import Traffic, load_mix
 
@@ -17,8 +19,11 @@ def test_matmul_params_of_qwen3_8b_one_chip_cut():
     layer = D * H * dh + 2 * D * K * dh + H * dh * D + 3 * D * F
     assert layer == 192_937_984 and D * V == 78_643_200
     L = CFG_1CHIP["num_hidden_layers"]
-    assert flops.matmul_params(CFG_1CHIP) == L * layer + D * V
-    assert flops.matmul_params(dict(CFG_1CHIP, num_hidden_layers=1)) == 271_581_184
+    assert flops.gqa_matmul_params(D, F, H, K, dh, dh, L, V) == L * layer + D * V
+    assert flops.gqa_matmul_params(D, F, H, K, dh, dh, 1, V) == 271_581_184
+    # a value head size of its own (MLA's 192/128) counts k and v apart
+    assert (flops.gqa_matmul_params(D, F, H, K, 192, 128, 1, V) - 271_581_184
+            == D * H * 64 + D * K * 64)
 
 
 def test_step_flops_are_six_per_parameter_per_document_token_plus_attention():
@@ -26,12 +31,16 @@ def test_step_flops_are_six_per_parameter_per_document_token_plus_attention():
     seg[0, :4000] = 1
     seg[1, :100], seg[1, 100:300] = 1, 2
     tokens = 4000 + 300
-    attn, _ = flops.attention_work(CFG_1CHIP, seg)
-    assert flops.step_flops(CFG_1CHIP, seg) == 6 * 271_581_184 * tokens + attn
+    w = qwen3.work(dict(CFG_1CHIP, num_hidden_layers=1), seg)
+    attn, nbytes = flops.attention_work(seg, 32, 8, 128, 128, 1)
+    assert (w["attn_flops"], w["attn_bytes"]) == (attn, nbytes)
+    assert w["flops"] == 6 * 271_581_184 * tokens + attn
 
 
-def _brute_force(seg, H, K, dh, L):
-    """Count the causal same-document pairs one query at a time."""
+def _brute_force(seg, H, K, dh_qk, dh_v, L):
+    """Count the causal same-document pairs one query at a time: forward
+    q k^T (2 dh_qk) and p v (2 dh_v) a pair and head, backward twice that;
+    q, k, v, o each read or written three times, in bf16."""
     pairs, tokens = 0, 0
     for row in seg:
         for i in range(len(row)):
@@ -39,19 +48,51 @@ def _brute_force(seg, H, K, dh, L):
                 continue
             tokens += 1
             pairs += sum(1 for j in range(i + 1) if row[j] == row[i])
-    return 12 * pairs * H * dh * L, (6 * H + 6 * K) * tokens * dh * 2 * L
+    fwd = pairs * H * (2 * dh_qk + 2 * dh_v)
+    moved = 3 * (H * dh_qk + K * dh_qk + K * dh_v + H * dh_v)
+    return 3 * fwd * L, moved * tokens * 2 * L
 
 
+@pytest.mark.parametrize("dh_qk,dh_v", [(128, 128), (192, 128)])
 @pytest.mark.parametrize("lens", [[5, 3, 8], [16], [1, 1, 2, 7], [9, 6]])
-def test_attention_work_matches_a_brute_force_count(lens):
-    cfg = dict(CFG_1CHIP, num_hidden_layers=3)
+def test_attention_work_matches_a_brute_force_count(lens, dh_qk, dh_v):
     seg = np.zeros((2, 16), np.int32)
     off = 0
     for i, n in enumerate(lens):
         seg[0, off:off + n] = i + 1
         off += n
     seg[1, 2:6] = 1  # a second row with leading padding
-    assert flops.attention_work(cfg, seg) == _brute_force(seg, 32, 8, 128, 3)
+    assert flops.attention_work(seg, 32, 8, dh_qk, dh_v, 3) == _brute_force(seg, 32, 8, dh_qk, dh_v, 3)
+
+
+def _parents_count(cfg, seg):
+    """qwen3's step work as the benchmark counted it before work was counted
+    by model type: (flops, attn_flops, attn_bytes)."""
+    D, F, H, K, dh, L = (cfg["hidden_size"], cfg["intermediate_size"], cfg["num_attention_heads"],
+                         cfg["num_key_value_heads"], cfg["head_dim"], cfg["num_hidden_layers"])
+    V = cfg["assumed"]["padded_vocab_size"]
+    params = L * (D * H * dh + 2 * D * K * dh + H * dh * D + 3 * D * F) + D * V
+    lens = flops.doc_lengths(seg)
+    pairs = int(np.sum(lens * (lens + 1) // 2))
+    attn_flops = 12 * pairs * H * dh * L
+    attn_bytes = (6 * H + 6 * K) * int(lens.sum()) * dh * 2 * L
+    tokens = int(np.sum(np.asarray(seg) > 0))
+    return 6 * params * tokens + attn_flops, attn_flops, attn_bytes
+
+
+def test_qwen3_work_is_the_parents_count_for_the_cells_first_batches():
+    from chipbench.runners import common
+
+    tr = CFG_1CHIP["train"]
+    traffic = Traffic(load_mix("packed"), tr["seq_len"], tr["global_batch"],
+                      CFG_1CHIP["vocab_size"], seed=2**33 + 5)
+    cfg = dict(CFG_1CHIP, name="qwen3-8b-1chip")
+    for step in range(12):
+        batch = traffic.batch(step)
+        w = common.step_work(cfg, batch)
+        assert (w["flops"], w["attn_flops"], w["attn_bytes"]) == _parents_count(
+            cfg, batch["segment_ids"]), step
+        assert all(type(w[k]) is int for k in ("flops", "attn_flops", "attn_bytes"))
 
 
 def test_peaks_refuse_an_unknown_device_kind():

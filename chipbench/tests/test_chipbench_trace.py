@@ -1,6 +1,7 @@
-"""The reduction from a profiler trace to busy/idle time, time under a scope
-and idle gaps by host span: on a hand-made record with known answers, and
-on a small trace recorded on a TPU v5e chip (tests/data)."""
+"""The reduction from a profiler trace to busy/idle time, time by innermost
+scope and idle gaps by host span: on a hand-made record with known answers,
+on a cut-down compiled program's text, and on a small trace recorded on a
+TPU v5e chip (tests/data)."""
 import pytest
 
 from chipbench import trace
@@ -42,8 +43,11 @@ def test_busy_is_the_union_of_overlapping_operations_inside_the_window():
 
 
 def test_time_under_a_scope_counts_each_operation_inside_the_window():
-    r = trace.reduce(_record())
+    r = trace.reduce(_record(), scopes=("attn_core", "mlp"))
     assert r["scope_s"]["attn_core"] == pytest.approx(0.020 + 0.005)
+    assert r["scope_s"]["mlp"] == pytest.approx(0.020 + 0.005)
+    assert r["scope_s"]["unscoped"] == pytest.approx(0.010 + 0.050)  # '' and 'x'
+    assert set(r["scope_s"]) == {"attn_core", "mlp", "unscoped"}
     ops = dict(r["device_ops"])
     assert ops["fusion.2 jit(step)/mlp/dot_general"] == pytest.approx(0.020)
     assert r["device_ops"][0] == ["fusion.9 x", pytest.approx(0.050)]
@@ -62,73 +66,78 @@ def test_idle_gaps_go_to_the_host_span_that_covers_most_of_them():
     assert sum(idle.values()) == pytest.approx(2 * 0.1 - 2 * 0.05)
 
 
-# A compiled step's text, cut down: an outer loop (the microbatches) holds
-# one attention op of its own and two loops with none inside them; one of
-# those holds attn_core ops in a fusion and a residual named outside it.
+# A compiled step's text, cut down: the microbatch loop's body holds the
+# attention core nested in the projections, a backward matmul under a
+# jvp/transpose wrap, a parameter path that names a layer but is no scope,
+# a scan residual and a copy named by no scope.
 HLO = """HloModule jit_train_step, entry_computation_layout={()->()}
 
 %fused_computation.1 (param_0: f32[8]) -> f32[8] {
   %param_0 = f32[8]{0} parameter(0)
-  ROOT %exponential.1 = f32[8]{0} exponential(%param_0), metadata={op_name="jit(step)/while/body/attn_core/exp"}
+  ROOT %exponential.1 = f32[8]{0} exponential(%param_0), metadata={op_name="jit(train_step)/while/body/attn_proj/attn_core/exp"}
 }
 
 %cond.1 (p: (s32[], f32[8])) -> pred[] {
   %p = (s32[], f32[8]) parameter(0)
-  ROOT %lt.1 = pred[] compare(%gte.0, %c.0), direction=LT, metadata={op_name="jit(step)/while/cond/lt"}
+  ROOT %lt.1 = pred[] compare(%gte.0, %c.0), direction=LT, metadata={op_name="jit(train_step)/while/cond/lt"}
 }
 
 %body.1 (p: (s32[], f32[8])) -> (s32[], f32[8]) {
   %p = (s32[], f32[8]) parameter(0)
-  %fusion.1 = f32[8]{0} fusion(%gte.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/while/body/attn_core/exp"}
-  %dynamic-update-slice.1 = f32[4,8]{1,0} dynamic-update-slice(%a, %fusion.1, %i, %z), metadata={op_name="jit(step)/while/body/dynamic_update_slice"}
+  %fusion.1 = f32[8]{0} fusion(%gte.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(train_step)/while/body/attn_proj/attn_core/exp"}
+  %fusion.2 = f32[8]{0} fusion(%gte.1), kind=kOutput, metadata={op_name="jit(train_step)/while/body/transpose(jvp(mlp))/bsd,df->bsf/dot_general"}
+  %fusion.3 = f32[8]{0} fusion(%gte.1), kind=kLoop, metadata={op_name="jit(train_step)/while/body/transpose(jvp(attn_proj))/attn_core/dot_general"}
+  %convert.1 = f32[8]{0} convert(%gte.1), metadata={op_name="jit(train_step)/state['params']['layers'][0]['mlp']/convert_element_type"}
+  %fusion.4 = f32[8]{0} fusion(%gte.1), kind=kLoop, metadata={op_name="jit(train_step)/while/body/mlp_gate/add"}
+  %dynamic-update-slice.1 = f32[4,8]{1,0} dynamic-update-slice(%a, %fusion.1, %i, %z), metadata={op_name="jit(train_step)/while/body/dynamic_update_slice"}
   %copy.1 = f32[8]{0} copy(%fusion.1)
   ROOT %tuple.1 = (s32[], f32[8]) tuple(%add.1, %copy.1)
 }
 
-%cond.2 (p: (s32[], f32[8])) -> pred[] {
-  %p = (s32[], f32[8]) parameter(0)
-  ROOT %lt.2 = pred[] compare(%gte.0, %c.0), direction=LT, metadata={op_name="jit(step)/while/cond/lt"}
-}
-
-%body.2 (p: (s32[], f32[8])) -> (s32[], f32[8]) {
-  %p = (s32[], f32[8]) parameter(0)
-  ROOT %fusion.2 = f32[8]{0} fusion(%gte.1), kind=kLoop, metadata={op_name="jit(step)/while/body/mlp/dot_general"}
-}
-
-%cond.3 (p: (s32[], f32[8])) -> pred[] {
-  %p = (s32[], f32[8]) parameter(0)
-  ROOT %lt.3 = pred[] compare(%gte.0, %c.0), direction=LT, metadata={op_name="jit(step)/while/cond/lt"}
-}
-
-%body.3 (p: (s32[], f32[8])) -> (s32[], f32[8]) {
-  %p = (s32[], f32[8]) parameter(0)
-  %fusion.3 = f32[8]{0} fusion(%x), kind=kLoop, metadata={op_name="jit(step)/while/body/attn_core/ne"}
-  %fusion.4 = f32[8]{0} fusion(%x), kind=kLoop, metadata={op_name="jit(step)/while/body/mlp/dot_general"}
-  %while.1 = (s32[], f32[8]) while(%t), condition=%cond.1, body=%body.1, metadata={op_name="jit(step)/while/body/while"}
-  ROOT %while.2 = (s32[], f32[8]) while(%t), condition=%cond.2, body=%body.2, metadata={op_name="jit(step)/while/body/while"}
-}
-
 ENTRY %main.1 (a: f32[8]) -> (s32[], f32[8]) {
   %a = f32[8]{0} parameter(0)
-  ROOT %while.3 = (s32[], f32[8]) while(%t), condition=%cond.3, body=%body.3, metadata={op_name="jit(step)/while"}
+  ROOT %while.1 = (s32[], f32[8]) while(%t), condition=%cond.1, body=%body.1, metadata={op_name="jit(train_step)/while"}
 }
 """
+SCOPES = ("attn_core", "attn_proj", "mlp", "lm_head", "optimizer")
 
 
-def test_an_innermost_loop_holding_a_scope_belongs_to_it_whole():
+@pytest.mark.parametrize("instr,scope", [
+    ("fusion.1", "attn_core"),  # nested in attn_proj: the innermost
+    ("fusion.2", "mlp"),  # inside transpose(jvp(...))
+    ("fusion.3", "attn_core"),  # nested in a wrapped scope
+    ("convert.1", None),  # a parameter path names no scope
+    ("fusion.4", None),  # a component that only starts with a scope's name
+    ("dynamic-update-slice.1", None),  # a scan's residual stays outside attention
+    ("lt.1", None),
+])
+def test_op_names_give_each_instruction_its_innermost_scope(instr, scope):
     (module, names), = trace.op_names(HLO).items()
     assert module == "jit_train_step"
-    # the attention loop: its residual, its plumbing and its condition too
-    for instr in ("dynamic-update-slice.1", "copy.1", "tuple.1", "lt.1"):
-        assert "[attn_core loop]" in names[instr], instr
-    assert names["fusion.1"] == "jit(step)/while/body/attn_core/exp"
-    # the other innermost loop holds no attention; the outer loop is not
-    # innermost, so only its own attention op counts
-    assert names["fusion.2"] == "jit(step)/while/body/mlp/dot_general"
-    assert names["fusion.4"] == "jit(step)/while/body/mlp/dot_general"
-    assert names["fusion.3"] == "jit(step)/while/body/attn_core/ne"
-    assert "lt.2" in names and "attn_core" not in names["lt.2"]
-    assert "attn_core" not in names["lt.3"]
+    assert trace.scope_of(names[instr], SCOPES) == scope
+    assert "copy.1" not in names and "tuple.1" not in names  # no metadata
+
+
+def test_reduce_splits_a_compiled_programs_operations_by_innermost_scope():
+    names = trace.op_names(HLO)["jit_train_step"]
+    instrs = ["fusion.1", "fusion.2", "fusion.3", "convert.1", "fusion.4",
+              "dynamic-update-slice.1", "copy.1"]
+    ops = [[i, k * MS, MS, names.get(i, "")] for k, i in enumerate(instrs)]
+    rec = {"devices": {"/device:TPU:0": ops}, "host": [["window", 0, 100 * MS]]}
+    r = trace.reduce(rec, scopes=SCOPES)
+    assert r["scope_s"] == pytest.approx({"attn_core": 0.002, "mlp": 0.001, "unscoped": 0.004})
+    assert sum(r["scope_s"].values()) == pytest.approx(r["busy_s"])
+
+
+def test_reduce_reads_the_programs_scope_names_when_it_runs(monkeypatch):
+    from repro import tracing
+
+    rec = {"devices": {"/device:TPU:0": [["fusion.1", 0, MS, "jit(step)/mlp/moe/dot_general"],
+                                          ["fusion.2", MS, MS, "jit(step)/attn_core/exp"]]},
+           "host": [["window", 0, 10 * MS]]}
+    assert trace.reduce(rec)["scope_s"] == pytest.approx({"mlp": 0.001, "attn_core": 0.001})
+    monkeypatch.setattr(tracing, "SCOPES", tracing.SCOPES + ("moe",))
+    assert trace.reduce(rec)["scope_s"] == pytest.approx({"moe": 0.001, "attn_core": 0.001})
 
 
 def test_a_trace_without_a_window_or_a_chip_is_refused():
@@ -159,22 +168,25 @@ def _sweep_busy(ops, lo, hi):
 
 
 def test_a_recorded_v5e_trace_reduces_to_its_union_scope_time_and_gaps():
-    """Three steps of the qwen3-8b.packed cell's window, traced on one TPU v5
-    lite chip, with scopes from the compiled step's text (op_names): the
-    chunked attention's scan counts whole, its residuals too."""
+    """Three steps of the qwen3-8b.packed cell's window (chunked attention
+    then), traced on one TPU v5 lite chip, with each operation's op_name from
+    the compiled step's text (op_names): the scopes partition operation time,
+    none counted twice."""
     rec = trace.read(RECORDED)
-    r = trace.reduce(rec)
+    r = trace.reduce(rec, scopes=SCOPES)
     (lo, hi), = [(s, s + d) for n, s, d in rec["host"] if n == "window"]
     (plane, ops), = rec["devices"].items()
     assert r["busy_s_per_chip"][plane] == pytest.approx(_sweep_busy(ops, lo, hi) / 1e9, abs=1e-9)
-    attn = sum(min(s + d, hi) - max(s, lo) for _, s, d, sc in ops
-               if "attn_core" in sc and min(s + d, hi) > max(s, lo))
+    clipped = [(min(s + d, hi) - max(s, lo), op) for _, s, d, op in ops if min(s + d, hi) > max(s, lo)]
+    attn = sum(t for t, op in clipped if trace.scope_of(op, SCOPES) == "attn_core")
     assert attn > 0 and r["scope_s"]["attn_core"] == pytest.approx(attn / 1e9, abs=1e-9)
     assert 0 < r["scope_s"]["attn_core"] < r["busy_s"] < r["window_s"]
-    # the scan's residual slices hold much of attention's time
-    loop = sum(min(s + d, hi) - max(s, lo) for _, s, d, sc in ops
-               if "[attn_core loop]" in sc and "dynamic" in sc and min(s + d, hi) > max(s, lo))
-    assert 0.4 < loop / attn < 0.7
+    # a partition: every operation in exactly one scope or 'unscoped'
+    assert sum(r["scope_s"].values()) == pytest.approx(sum(t for t, _ in clipped) / 1e9, abs=1e-9)
+    assert set(r["scope_s"]) <= set(SCOPES) | {"unscoped"}
+    # the chunked scan's residual slices are named outside attn_core
+    residual = sum(t for t, op in clipped if "dynamic" in op and "rematted" in op)
+    assert residual > 0 and r["scope_s"]["unscoped"] * 1e9 > residual
     idle = dict(r["idle_gaps"])
     assert sum(idle.values()) == pytest.approx(r["window_s"] - r["busy_s"], abs=1e-6)
     # the device waits on the host between steps, while it blocks on the

@@ -2,25 +2,28 @@
 
 `load` reads the `.xplane.pb` that `jax.profiler` writes into a small
 record: for each TPU chip, its operations as [name, start_ns, duration_ns,
-scope]; and the benchmark's own host spans as [name, start_ns,
+op_name]; and the benchmark's own host spans as [name, start_ns,
 duration_ns]. Host and device times share one clock in the trace. A TPU
 trace names each operation by its HLO instruction and carries no name
-stack, so the scope (where `jax.named_scope` names appear) comes from the
-`op_name` metadata of the compiled programs' text (`op_names`), matched by
-module and instruction. An operation in an innermost loop (a while loop
-with no loop inside it) that holds operations of a scope belongs to that
-scope too: JAX names the residuals a scan writes and reads (its
-dynamic_update_slice and dynamic_slice) outside the scope the scan was
-built in, though they are the scan's work. Control-flow containers (while,
-conditional, call) span the operations of their bodies and are left out.
+stack, so the name stack comes from the `op_name` metadata of the compiled
+programs' text (`op_names`), matched by module and instruction.
+Control-flow containers (while, conditional, call) span the operations of
+their bodies and are left out.
 
 `reduce` takes the window from the host span named 'window' and gives:
 - busy_s per chip: the union of the chip's operation intervals inside the
   window (overlapping operations count once), and window_s;
-- scope_s: device seconds of operations whose scope holds a given name;
+- scope_s: device seconds of the operations inside the window by the
+  innermost program scope in their name stack (`scope_of`), summed over the
+  chips; an operation under no scope counts under 'unscoped', so each
+  operation counts once;
 - device_ops: the operations that took most device time, by name;
 - idle_gaps: the window's idle device time, each gap given to the innermost
   benchmark span that covers most of it ('none' where no span does).
+
+The scope names are the program's own (`repro.tracing.SCOPES`), read when a
+trace is reduced, so a scope the program adds is read with no edit here; how
+an operation is matched to a scope is this module's.
 """
 from __future__ import annotations
 
@@ -33,16 +36,13 @@ import re
 from collections import defaultdict
 
 HOST_SPANS = ("window", "batch_to_device", "train_step", "block")
-SCOPES = ("attn_core",)
+UNSCOPED = "unscoped"
 OP_LINE, MODULE_LINE = "XLA Ops", "XLA Modules"
 CONTAINERS = ("while", "conditional", "call")
 _INSTR = re.compile(r"%([^ ]+) = ")
-_COMPUTATION = re.compile(r"^(?:ENTRY )?%([^ ]+) ")
 _DEF = re.compile(r"^\s*(?:ROOT )?%([^ ]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
-_CALLED = re.compile(r"(?:calls|body|condition|to_apply)=%([^\s,)}]+)")
-_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
-_WHILE = re.compile(r"[)\]}] while\(.*condition=%([^\s,]+), body=%([^\s,]+)")
+_WRAPPED = re.compile(r"^[\w.]+\((.*)\)$")
 
 
 def options():
@@ -61,58 +61,28 @@ def find_xplane(directory):
     return max(found, key=os.path.getmtime)
 
 
-def _computations(hlo_text):
-    """{computation: [(instruction, op_name, computations it calls,
-    (condition, body) for a while or None)]}, and the module's name."""
+def scope_of(op_name, scopes):
+    """The innermost of `scopes` in an operation's name stack, or None.
+
+    A scope counts only as a whole component of the stack, also when JAX
+    wraps it as `jvp(mlp)` or `transpose(jvp(mlp))`; a parameter path such as
+    `state['params']['layers'][0]['ffn']` names no scope."""
+    for part in reversed(op_name.split("/")):
+        while (m := _WRAPPED.match(part)):
+            part = m.group(1)
+        if part in scopes:
+            return part
+    return None
+
+
+def op_names(hlo_text) -> dict:
+    """{module: {instruction: op_name}} from a compiled program's text."""
     module = hlo_text.split(None, 2)[1].rstrip(",")
-    comps, current = {}, None
+    names = {}
     for line in hlo_text.splitlines():
-        m = _COMPUTATION.match(line)
-        if m and line.rstrip().endswith("{"):
-            current = comps.setdefault(m.group(1), [])
-            continue
-        m = _DEF.match(line)
-        if m and current is not None:
-            meta = _OP_NAME.search(line)
-            called = _CALLED.findall(line)
-            for group in _BRANCHES.findall(line):
-                called += [c.strip().lstrip("%") for c in group.split(",")]
-            loop = _WHILE.search(line)
-            current.append((m.group(1), meta.group(1) if meta else "", called,
-                            loop.groups() if loop else None))
-    return module, comps
-
-
-def op_names(hlo_text, scopes=SCOPES) -> dict:
-    """{module: {instruction: scope}} from a compiled program's text: each
-    instruction's op_name, and for the operations of an innermost loop that
-    holds operations of a scope, '<op_name> [<scope> loop]'."""
-    module, comps = _computations(hlo_text)
-    names = {i: op for body in comps.values() for i, op, _, _ in body if op}
-    reach = {}
-
-    def reachable(c):
-        if c not in reach:
-            reach[c] = {c}
-            for _, _, called, _ in comps.get(c, []):
-                for d in called:
-                    reach[c] |= reachable(d)
-        return reach[c]
-
-    for body in list(comps.values()):
-        for _, _, _, loop in body:
-            if loop is None:
-                continue
-            inside = reachable(loop[0]) | reachable(loop[1])
-            ops = [x for c in inside for x in comps.get(c, [])]
-            if any(x[3] for x in ops):
-                continue  # not innermost
-            for sc in scopes:
-                if any(sc in x[1] for x in ops):
-                    for c in loop:
-                        for instr, op, _, _ in comps.get(c, []):
-                            if sc not in op:
-                                names[instr] = f"{op} [{sc} loop]"
+        d, meta = _DEF.match(line), _OP_NAME.search(line)
+        if d and meta:
+            names[d.group(1)] = meta.group(1)
     return {module: names}
 
 
@@ -127,7 +97,7 @@ def _is_container(instr):
 
 
 def load(path, names=None) -> dict:
-    """Read an .xplane.pb into {'devices': {plane: [[instr, start, dur, scope]]},
+    """Read an .xplane.pb into {'devices': {plane: [[instr, start, dur, op_name]]},
     'host': [[name, start, dur]]}; `names` as op_names gives."""
     from jax.profiler import ProfileData
 
@@ -199,14 +169,20 @@ def _owner(gap, spans):
     return best
 
 
-def _label(instr, scope):
+def _label(instr, op_name):
     """An operation's name for the breakdown: its instruction and the end of
     its name stack."""
-    tail = "/".join(scope.split("/")[-3:])
+    tail = "/".join(op_name.split("/")[-3:])
     return f"{instr} {tail}".strip()
 
 
-def reduce(record, scopes=SCOPES, top=10) -> dict:
+def reduce(record, scopes=None, top=10) -> dict:
+    """The window's numbers (module docstring); `scopes` defaults to the
+    program's, as they are when this runs."""
+    if scopes is None:
+        from repro import tracing
+
+        scopes = tracing.SCOPES
     windows = [(s, s + d) for n, s, d in record["host"] if n == "window"]
     if not windows:
         raise ValueError("the trace holds no 'window' span")
@@ -215,15 +191,13 @@ def reduce(record, scopes=SCOPES, top=10) -> dict:
     busy_s, scope_s, op_s, idle = {}, defaultdict(float), defaultdict(float), defaultdict(float)
     for plane, ops in sorted(record["devices"].items()):
         inside = []
-        for name, s, d, scope in ops:
+        for name, s, d, op_name in ops:
             a, b = max(s, lo), min(s + d, hi)
             if b <= a:
                 continue
             inside.append((a, b))
-            op_s[_label(name, scope)] += (b - a) / 1e9
-            for sc in scopes:
-                if sc in scope:
-                    scope_s[sc] += (b - a) / 1e9
+            op_s[_label(name, op_name)] += (b - a) / 1e9
+            scope_s[scope_of(op_name, scopes) or UNSCOPED] += (b - a) / 1e9
         busy = _union(inside)
         busy_s[plane] = sum(e - s for s, e in busy) / 1e9
         for gap in _gaps(busy, lo, hi):
